@@ -309,13 +309,16 @@ class RunConfig:
     # attention implementation: xla | chunked | pallas | pallas_interpret
     attention_impl: str = "chunked"
     # decode-step attention (the serving hot loop, one token vs KV cache):
-    #   einsum           — masked-softmax einsum over the full cache; the
-    #                      CPU/reference fallback and the default
+    #   auto             — chosen from the platform when the step is traced
+    #                      (models.attention.resolve_decode_impl): the
+    #                      kernel on TPU, einsum elsewhere; the default
+    #   einsum           — masked-softmax einsum over the full cache
     #   kernel           — Pallas flash-decode (kernels/decode_attention.py),
     #                      one streaming pass over K/V with the per-slot
     #                      ring/partial-fill valid mask; TPU only
-    #   kernel_interpret — same kernel in interpret mode (CPU parity tests)
-    decode_attention_impl: str = "einsum"
+    #   kernel_interpret — same kernel in interpret mode (CPU parity tests;
+    #                      refused on a TPU)
+    decode_attention_impl: str = "auto"
     attention_chunk: int = 1024
     ssd_chunk: int = 256  # SSD/mLSTM chunk length
     # unroll inner (attention/ssd) scans — used by dry-run cost probes so

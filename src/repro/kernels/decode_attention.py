@@ -14,6 +14,12 @@ Two modes:
 
 The per-batch ``valid`` mask handles ring buffers (sliding-window caches)
 and partially-filled caches without any host-side slicing.
+
+Block layout: the TPU lowering requires the last two dimensions of every
+block to be divisible by (8, 128) or equal to the array's. So the mask and
+the ``m``/``l`` outputs carry a unit middle axis — ``(BKH, 1, S)`` and
+``(BKH, 1, G)`` with ``(1, 1, ·)`` blocks — rather than ``(1, ·)`` blocks
+over a 2-D ``(BKH, ·)`` array, which the chip's compiler refuses.
 """
 
 from __future__ import annotations
@@ -32,10 +38,10 @@ def _decode_kernel(
     q_ref,  # (1, G, D)
     k_ref,  # (1, bk, D)
     v_ref,  # (1, bk, D)
-    valid_ref,  # (1, bk) int32 (bool as int)
+    valid_ref,  # (1, 1, bk) int32 (bool as int)
     o_ref,  # (1, G, D)
-    m_ref,  # (1, G)
-    l_ref,  # (1, G)
+    m_ref,  # (1, 1, G)
+    l_ref,  # (1, 1, G)
     m_scr,  # (G,) f32
     l_scr,  # (G,) f32
     acc_scr,  # (G, D) f32
@@ -58,8 +64,8 @@ def _decode_kernel(
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # (G, bk)
-    ok = valid_ref[0] > 0  # (bk,)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    ok = valid_ref[0] > 0  # (1, bk)
+    s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1))
@@ -72,7 +78,7 @@ def _decode_kernel(
     # or the zero-padded seq_len % block_k remainder of the last block —
     # leaks the phantom mass into l (and, unnormalized, into the partials
     # the cross-shard combine consumes).
-    p = jnp.where(ok[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=1)
     pv = jax.lax.dot_general(
@@ -88,15 +94,15 @@ def _decode_kernel(
             o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
         else:
             o_ref[0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0] = m_scr[...].astype(m_ref.dtype)
-        l_ref[0] = l_scr[...].astype(l_ref.dtype)
+        m_ref[0] = m_scr[...][None, :].astype(m_ref.dtype)
+        l_ref[0] = l_scr[...][None, :].astype(l_ref.dtype)
 
 
 def decode_attention_fwd(
     q: jax.Array,  # (BKH, G, D)   — q heads grouped per kv head
     k: jax.Array,  # (BKH, S, D)
     v: jax.Array,
-    valid: jax.Array,  # (BKH, S) int32
+    valid: jax.Array,  # (BKH, 1, S) int32
     *,
     scale: float,
     block_k: int = 512,
@@ -111,7 +117,7 @@ def decode_attention_fwd(
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
+        valid = jnp.pad(valid, ((0, 0), (0, 0), (0, pad)))
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, num_k_blocks=nk, normalize=normalize
@@ -123,17 +129,17 @@ def decode_attention_fwd(
             pl.BlockSpec((1, g, d), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk), lambda b, j: (b, j)),
+            pl.BlockSpec((1, 1, bk), lambda b, j: (b, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, g, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, g), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, g), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, g), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, g), lambda b, j: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bkh, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((bkh, g), jnp.float32),
-            jax.ShapeDtypeStruct((bkh, g), jnp.float32),
+            jax.ShapeDtypeStruct((bkh, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((bkh, 1, g), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),

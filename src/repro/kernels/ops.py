@@ -104,7 +104,7 @@ def decode_attention(
     qf = q.reshape(b, kh, g, d).reshape(b * kh, g, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * kh, s, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * kh, s, d)
-    validf = jnp.repeat(valid.astype(jnp.int32), kh, axis=0).reshape(b * kh, s)
+    validf = jnp.repeat(valid.astype(jnp.int32), kh, axis=0).reshape(b * kh, 1, s)
     out, m, l = decode_attention_fwd(
         qf, kf, vf, validf, scale=scale, block_k=block_k,
         normalize=not return_partials, interpret=interpret,

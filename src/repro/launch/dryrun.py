@@ -45,7 +45,7 @@ RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
 def _compile_cell(cfg, run, shape, mesh):
     art = cell_artifacts(cfg, run, shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             art["fn"],
             in_shardings=art["in_shardings"],

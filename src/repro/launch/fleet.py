@@ -60,8 +60,11 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import itertools
 import time
 from typing import Mapping, Optional, Sequence, Union
+
+import jax
 
 from repro.core.admission import (
     ADMIT,
@@ -771,6 +774,10 @@ def build_fleet(
 ) -> FleetLoop:
     """N identical ``ServeLoop`` replicas behind one :class:`FleetLoop`.
 
+    Replica *i* lives on ``jax.devices()[i % n]``: its params are placed
+    there (one copy per device, shared by the replicas on it), and its
+    arena and steps follow them. The factory counts every replica it
+    builds, so an autoscaler spawn continues the same round-robin.
     Replica-level admission is ``None`` by construction: the fleet door is
     the only place a request is judged (the same no-private-path rule the
     admission layer enforces single-replica). The ``replica_factory``
@@ -781,9 +788,16 @@ def build_fleet(
     measures, so a faster decode path re-prices every capacity-gated
     policy with no fleet-side change."""
 
+    devices = jax.devices()
+    built = itertools.count()
+    placed: dict = {}  # device -> params committed there
+
     def factory():
+        dev = devices[next(built) % len(devices)]
+        if dev not in placed:
+            placed[dev] = jax.device_put(params, dev)
         return ServeLoop(
-            cfg, run, params, batch=batch, max_len=max_len,
+            cfg, run, placed[dev], batch=batch, max_len=max_len,
             admission=None, batched=batched, mode=mode,
         )
 
@@ -795,12 +809,10 @@ def build_fleet(
 
 
 def main(argv=None) -> dict:
-    import jax
-    import numpy as np  # noqa: F401  (Request prompts are np arrays)
-
     from repro.configs import get_config
     from repro.configs.base import RunConfig
     from repro.data.dataset import SyntheticCorpus
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
 
     ap = argparse.ArgumentParser()
@@ -824,6 +836,7 @@ def main(argv=None) -> dict:
                          "requests (core.router.plan_hedge)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     run = RunConfig(remat="none", attention_impl="xla",
                     ssd_chunk=min(256, args.prompt_len))

@@ -8,6 +8,7 @@ dry-run must set XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # XLA flags we set on real TPU deployments for collective/compute overlap.
 # (Harmless no-ops on CPU; recorded here so launch scripts share one source.)
@@ -25,14 +26,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     """The assignment's production mesh: 16×16 per pod, 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...] | None = None):
-    """Arbitrary mesh for tests / small dry-runs."""
+    """Arbitrary mesh for tests / small dry-runs.
+
+    Axes are ``Auto``: the sharding rules place arrays through
+    ``with_sharding_constraint`` and leave the rest to the partitioner,
+    which ``jax.make_mesh``'s default ``Explicit`` axes would reject."""
     if axes is None:
         axes = {1: ("model",), 2: ("data", "model"), 3: ("pod", "data", "model")}[len(shape)]
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def parse_mesh_arg(arg: str):

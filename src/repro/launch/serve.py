@@ -73,6 +73,7 @@ from repro.core.admission import (
     trailing_class_p99,
 )
 from repro.data.dataset import SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
@@ -229,24 +230,32 @@ class ServeLoop:
         self._decode_arena = jax.jit(_arena_decode)
         self._write_slot = jax.jit(_slot_write)
 
+    def _new_arena(self):
+        """A zeroed arena on the params' device. The replica runs where its
+        params live (the fleet places each replica on its own chip): the
+        arena is built there, and host inputs go to the jitted steps as
+        numpy so that they land beside the params, not on device 0."""
+        device = next(iter(jax.tree.leaves(self.params)[0].devices()))
+        with jax.default_device(device):
+            return M.init_cache(self.cfg, self.batch, self.max_len)
+
     def _warm(self, prompt_len: int) -> None:
         """Compile prefill (B=1) and decode at every group width once,
         *before* the measured window opens: a first-hit XLA compile inside
         the serve loop stalls decoding mid-run and lands a compile-dominated
         sample in the capacity EMA — which capacity-gated policies then
         act on permanently (an offer is final)."""
-        tok = jnp.zeros((1, prompt_len), jnp.int32)
+        tok = np.zeros((1, prompt_len), np.int32)
         _, cache = self.prefill(self.params, tok)
         if self.mode == "arena":
             # one decode width exists (the full arena) — compile the slot
             # write and the fused decode+argmax once; a throwaway arena so
             # repeated warms (one per distinct prompt length) stay cheap
-            arena = M.init_cache(self.cfg, self.batch, self.max_len)
-            arena = self._write_slot(arena, cache, 0)
+            arena = self._write_slot(self._new_arena(), cache, 0)
+            act = np.zeros((self.batch,), bool)
+            act[0] = True
             self._decode_arena(
-                self.params, arena,
-                jnp.zeros((self.batch, 1), jnp.int32),
-                jnp.zeros((self.batch,), bool).at[0].set(True),
+                self.params, arena, np.zeros((self.batch, 1), np.int32), act
             )
             return
         widths = range(1, self.batch + 1) if self.batched else (1,)
@@ -536,7 +545,9 @@ class ServeLoop:
             self._slot_rid[s] = r.rid
             self._prefill_skipped += 1
             return
-        logits, cache = self.prefill(self.params, jnp.asarray(r.prompt[None]))
+        logits, cache = self.prefill(
+            self.params, np.asarray(r.prompt[None], np.int32)
+        )
         tok = int(jnp.argmax(logits[0, -1]))
         r.tokens.append(tok)
         r.first_token = self.now()
@@ -544,7 +555,7 @@ class ServeLoop:
             # join at a token boundary: claim the lowest free slot, index-
             # write the prefilled cache in — no regroup, no recompile
             if self._arena is None:
-                self._arena = M.init_cache(self.cfg, self.batch, self.max_len)
+                self._arena = self._new_arena()
             if not self._free_slots and self._session_slot:
                 # slot pressure: evict the least-recently-parked session —
                 # a live decode always outranks a speculative future turn
@@ -589,9 +600,9 @@ class ServeLoop:
         """One decode step for the whole arena: a single dispatch advances
         every occupied slot, whatever mix of positions they sit at."""
         act = np.array([rid is not None for rid in self._slot_rid])
-        toks = jnp.asarray(self._slot_last[:, None].astype(np.int32))
+        toks = self._slot_last[:, None].astype(np.int32)
         new_toks, self._arena = self._decode_arena(
-            self.params, self._arena, toks, jnp.asarray(act)
+            self.params, self._arena, toks, act
         )
         self._decode_calls += 1
         self._occ_sum += int(act.sum())
@@ -779,6 +790,7 @@ def main(argv=None) -> dict:
                          "bit-exact single-request reference path")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     run = RunConfig(remat="none", attention_impl="xla", ssd_chunk=min(256, args.prompt_len))
     params = M.init_model(jax.random.PRNGKey(args.seed), cfg)

@@ -15,11 +15,11 @@ the cache sequence dimension sharded over the ``model`` mesh axis so that
 XLA's partial-softmax collectives implement cross-chip flash-decode (see
 DESIGN.md §3). The decode step takes a per-slot *position vector*, so one
 dispatch serves a continuous batch whose rows sit at different cache
-positions, and dispatches on ``RunConfig.decode_attention_impl``:
-``kernel`` / ``kernel_interpret`` route through the Pallas flash-decode
-kernel (`repro.kernels.decode_attention`) with the per-row ring/partial-fill
-``valid`` mask; ``einsum`` is the CPU/reference fallback, asserted bit-close
-in tests/test_models.py.
+positions. Its attention is chosen from the platform
+(:func:`resolve_decode_impl`): on a TPU the Pallas flash-decode kernel
+(`repro.kernels.decode_attention`) with the per-row ring/partial-fill
+``valid`` mask, elsewhere the masked-softmax einsum, asserted bit-close to
+the kernel's interpret mode in tests/test_consistency.py.
 """
 
 from __future__ import annotations
@@ -310,6 +310,22 @@ def attn_fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -
     }
 
 
+def resolve_decode_impl(impl: str) -> str:
+    """The decode-attention implementation for the platform this traces on.
+
+    ``auto`` is the Pallas kernel on a TPU and the einsum elsewhere (the
+    kernel has no compiled CPU lowering). An explicit name is honoured, so
+    tests and compile rehearsals can pin one path; interpret mode is for
+    CPU tests and is refused on a TPU, where it would silently run the
+    kernel as slow emulated XLA."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "auto":
+        return "kernel" if on_tpu else "einsum"
+    if impl == "kernel_interpret" and on_tpu:
+        raise ValueError("kernel_interpret is for CPU tests; a TPU runs the compiled kernel")
+    return impl
+
+
 def attn_apply_step(
     cfg: ModelConfig,
     run: RunConfig,
@@ -352,7 +368,7 @@ def attn_apply_step(
         valid = idx[None, :] <= slot[:, None]
 
     scale = 1.0 / cfg.head_dim_**0.5
-    impl = run.decode_attention_impl
+    impl = resolve_decode_impl(run.decode_attention_impl)
     if impl in ("kernel", "kernel_interpret"):
         from repro.kernels import ops as kops
 

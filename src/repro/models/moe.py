@@ -98,7 +98,7 @@ def moe_apply(
     # dispatch tensor (ng, g, E, C): for each token/k slot, one-hot over (e, c).
     # Built in compute dtype: 0/1 values and top-k gates are exactly/safely
     # representable in bf16, and this tensor dominates MoE activation bytes.
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=dt)  # (ng, g, k, C)
+    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=dt)  # (ng, g, k, C)
     disp = jnp.einsum("gske,gskc->gsec", (sel * keep[..., None]).astype(dt), pos_oh)
     comb = jnp.einsum("gske,gskc,gsk->gsec", sel.astype(dt), pos_oh, gates.astype(dt))
 

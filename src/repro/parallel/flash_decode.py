@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops as kops
 
@@ -65,7 +64,7 @@ def sharded_decode_attention(
         den = jax.lax.psum(l * w, axis)
         return (num / jnp.maximum(den, 1e-30)[..., None]).astype(q_l.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -75,5 +74,5 @@ def sharded_decode_attention(
             P(bspec, axis),
         ),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, valid)

@@ -28,7 +28,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -102,10 +101,10 @@ def pipeline_apply(
 
     other_axes = [a for a in mesh.axis_names if a != stage_axis]
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         staged,
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x)

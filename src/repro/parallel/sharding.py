@@ -133,20 +133,10 @@ class ShardingRules:
 # ---------------------------------------------------------------------------
 
 
-def _active_mesh() -> Optional[Mesh]:
-    # jax.sharding.get_abstract_mesh landed after 0.4.37 — fall through to
-    # the thread-resources env mesh on older versions (this container)
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = get_abstract() if get_abstract is not None else None
-    try:
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    env_mesh = getattr(jax.interpreters.pxla, "thread_resources", None)
-    if env_mesh is not None and not env_mesh.env.physical_mesh.empty:
-        return env_mesh.env.physical_mesh
-    return None
+def _active_mesh():
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def rules_from_mesh(mesh: Mesh, fsdp: bool = True, sequence_parallel: bool = True) -> ShardingRules:

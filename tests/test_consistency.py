@@ -102,7 +102,9 @@ def test_decode_attention_impls_agree():
             logits.append(lt)
         outs[impl] = jnp.stack(logits)
         assert cache["pos"].tolist() == [14, 14, 10]  # active mask honoured
-    err = float(jnp.abs(outs["einsum"] - outs["kernel_interpret"]).max())
+    # the parked row's logits are garbage by contract (decode_step): the
+    # kernel zeroes an all-invalid row, the einsum averages it uniformly
+    err = float(jnp.abs(outs["einsum"][:, active] - outs["kernel_interpret"][:, active]).max())
     assert err < 2e-5, f"decode impl divergence: {err}"
 
 

@@ -1,12 +1,7 @@
 """Multi-device tests (8 placeholder host devices via subprocess — the
 XLA device count must be set before jax initializes, so these run in
-spawned interpreters).
-
-jax-version note: these tests failed on jax 0.4.37 because
-``parallel/sharding._active_mesh`` called ``jax.sharding.get_abstract_mesh``
-unconditionally (added in a later jax). Rather than version-gating the
-tests, the source now feature-detects it and falls back to the
-thread-resources env mesh, so this whole module is green on 0.4.37."""
+spawned interpreters). Sharded steps run under ``jax.set_mesh``, the
+context ``parallel/sharding._active_mesh`` reads."""
 
 import json
 import os
@@ -84,7 +79,7 @@ def test_sharded_train_step_matches_single_device():
         mesh = make_mesh((2, 4))
         rules = rules_from_mesh(mesh)
         pspecs = M.model_specs(cfg, rules)
-        with mesh:
+        with jax.set_mesh(mesh):
             step = jax.jit(make_train_step(cfg, run, rules))
             p2, o2, m2 = step(params, opt, batch)
         dl = abs(float(m1["loss"]) - float(m2["loss"]))

@@ -127,6 +127,58 @@ def test_decode_all_invalid_row_returns_zero(rng):
     assert float(l[1].max()) == 0.0  # partials come back (B, H): row 1 empty
 
 
+def test_decode_mask_layout_edge_rows(rng):
+    """The kernel's (B·KH, 1, S) mask and (B·KH, 1, G) m/l layout, through
+    ops.decode_attention, on the edge rows of one call: a wrapped ring
+    (every slot valid, a remainder block), a ring whose valid slots
+    straddle the wrap point, a row valid only in the tail, and a parked
+    row. Normalized output matches the reference on live rows and is zero
+    on the parked one; partials keep their (B, H) contract with l = 0 on
+    the parked row, and combining two sequence halves — one of them empty
+    for the tail-only row — reproduces the monolithic result."""
+    B, S, H, KH, D, bk = 4, 200, 4, 2, 64, 64
+    q = _arr(rng, B, H, D)
+    k = _arr(rng, B, S, KH, D)
+    v = _arr(rng, B, S, KH, D)
+    idx = jnp.arange(S)
+    valid = jnp.stack(
+        [jnp.ones(S, bool), (idx < 37) | (idx >= 150), idx >= S - 3, jnp.zeros(S, bool)]
+    )
+    exp = ref.decode_attention_ref(q, k, v, valid)
+    out = ops.decode_attention(q, k, v, valid, block_k=bk, interpret=True)
+    assert float(jnp.abs(out[:3] - exp[:3]).max()) < 2e-5
+    assert float(jnp.abs(out[3]).max()) == 0.0
+
+    o, m, l = ops.decode_attention(
+        q, k, v, valid, block_k=bk, return_partials=True, interpret=True
+    )
+    assert m.shape == l.shape == (B, H)
+    assert bool(jnp.isfinite(o).all()) and float(l[3].max()) == 0.0
+    parts = [
+        ops.decode_attention(
+            q, k[:, sl], v[:, sl], valid[:, sl], block_k=bk,
+            return_partials=True, interpret=True,
+        )
+        for sl in (slice(0, S // 2), slice(S // 2, S))
+    ]
+    combined = ops.combine_decode_partials(*zip(*parts))
+    assert float(jnp.abs(combined[:3] - exp[:3]).max()) < 2e-5
+    assert float(jnp.abs(combined[3]).max()) == 0.0
+
+
+def test_decode_attention_impl_follows_platform(monkeypatch):
+    """The decode attention is chosen from the platform: the kernel on a
+    TPU, the einsum elsewhere; interpret mode is refused on a TPU."""
+    from repro.models import attention
+
+    assert attention.resolve_decode_impl("auto") == "einsum"  # tests run on the CPU
+    assert attention.resolve_decode_impl("kernel_interpret") == "kernel_interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attention.resolve_decode_impl("auto") == "kernel"
+    with pytest.raises(ValueError):
+        attention.resolve_decode_impl("kernel_interpret")
+
+
 def test_decode_partials_combine(rng):
     """Shard the cache in two, combine partials, compare to monolithic."""
     B, S, H, KH, D = 2, 256, 4, 2, 64

@@ -147,5 +147,6 @@ def decode_attention_fwd(
             pltpu.VMEM((g, d), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(q, k, v, valid)
     return out, m, l

@@ -35,6 +35,18 @@ toward per-slot dispatch — the regime claim 14 in
 its own dispatch — the bit-exact single-request reference the continuous-
 batching tests compare token streams against.
 
+**Tracing.** Every ``tick()`` is a ``serve.tick`` span of
+``jax.profiler``, so a trace puts the host's phases on the device's clock.
+Inside it, arena mode opens ``serve.decode`` (arguments ``rows``, the
+active slots, and ``kv_tokens``, their valid cache positions with the new
+token's), ``serve.decode.sync``, ``serve.emit``, ``serve.pump``, and per
+admitted request ``serve.admit`` (argument ``tokens``, the prompt length,
+0 on a parked-slot hit) holding ``serve.prefill``, ``serve.first_token``
+and ``serve.slot_write``; the legacy modes open ``serve.pump`` and
+``serve.admit`` alone. With no profiler running a span costs about a
+microsecond. The same timers keep, always on, the session's longest tick
+with each phase's own seconds (``stats()["slowest_tick"]``).
+
 Caveat: the arena masks *positions*, not expert routing — on MoE
 architectures parked slots still consume router capacity, so arena mode is
 exact for attention/SSM stacks and approximate under MoE capacity drops
@@ -50,6 +62,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import heapq
 import math
 import time
@@ -174,6 +187,40 @@ def _slot_write(arena, one, slot):
         arena["pos"], one["pos"].astype(arena["pos"].dtype), slot, axis=0
     )
     return {"pos": pos, "layers": layers}
+
+
+class _Phase:
+    """One phase of a tick (or of ``start()``'s first admissions). It opens
+    a ``jax.profiler.TraceAnnotation`` (a host span on the device trace's
+    clock while the profiler runs, about a microsecond when it does not)
+    and adds its own ``perf_counter`` seconds, less those of the phases
+    opened inside it, to ``split[name]``. ``open_`` is the stack of the
+    phases open around it."""
+
+    __slots__ = ("_name", "_split", "_open", "_ann", "_t", "_inner")
+
+    def __init__(self, name: str, split: dict, open_: list, **args):
+        self._name, self._split, self._open = name, split, open_
+        self._ann = jax.profiler.TraceAnnotation(name, **args)
+
+    def note(self, **args) -> None:
+        """Arguments known only once the phase is under way."""
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._open.append(self)
+        self._inner = 0.0
+        self._t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t
+        self._open.pop()
+        if self._open:
+            self._open[-1]._inner += dt
+        self._split[self._name] = self._split.get(self._name, 0.0) + dt - self._inner
+        self._ann.__exit__(*exc)
 
 
 class ServeLoop:
@@ -332,11 +379,30 @@ class ServeLoop:
         # threshold/token_bucket policies by an order of magnitude
         self._tok_rate = 0.0
         self._peak_rate = 0.0
+        # phase split of the current tick (own seconds per span name), the
+        # phases open now, and the longest tick so far with its split
+        self._split: dict[str, float] = {}
+        self._open: list[_Phase] = []
+        self._slowest: Optional[dict] = None
+        # Σ valid cache positions of the active slots, kept as a running
+        # count: slot s holds _slot_base[s] + len(tokens of its request)
+        self._kv_tokens = 0
+        self._slot_base = np.zeros(self.batch, np.int64)
         self._pump()
         self._fill_slots()
 
     def now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def _phase(self, name: str, **args) -> _Phase:
+        return _Phase(name, self._split, self._open, **args)
+
+    def _inner_phase(self, name: str, **args):
+        """A phase that only arena mode splits out; the legacy modes trace
+        their tick, pump and admissions alone."""
+        if self.mode == "arena":
+            return self._phase(name, **args)
+        return contextlib.nullcontext()
 
     @property
     def tok_rate(self) -> float:
@@ -425,6 +491,7 @@ class ServeLoop:
             # them, which is the whole point of the allocator
             for s, orid in enumerate(self._slot_rid):
                 if orid == rid:
+                    self._leave(s, self._by_id[rid])
                     self._release_slot(s)
                     found = True
                     break
@@ -506,20 +573,21 @@ class ServeLoop:
         real measurement, without judging the whole queue on a guess.
         ``force`` lifts the bound for the endgame drain — when nothing
         will ever run again, the guess is all there is."""
-        if self._policy is None:
+        with self._phase("serve.pump"):
+            if self._policy is None:
+                while self._pending:
+                    self._ready.append(self._pending.popleft())
+                return
             while self._pending:
-                self._ready.append(self._pending.popleft())
-            return
-        while self._pending:
-            if self._tok_rate <= 0 and not force and self._offered >= self.batch:
-                break
-            r = self._pending.popleft()
-            self._offered += 1
-            decision = self._policy.offer(self.as_job_request(r), self._view(self.now()))
-            if decision != DEFER:
-                self._resolve(r, decision)
-        for req, decision in self._policy.poll(self._view(self.now())):
-            self._resolve(self._by_id[req.job_id], decision)
+                if self._tok_rate <= 0 and not force and self._offered >= self.batch:
+                    break
+                r = self._pending.popleft()
+                self._offered += 1
+                decision = self._policy.offer(self.as_job_request(r), self._view(self.now()))
+                if decision != DEFER:
+                    self._resolve(r, decision)
+            for req, decision in self._policy.poll(self._view(self.now())):
+                self._resolve(self._by_id[req.job_id], decision)
 
     def _on_done(self, r: Request) -> None:
         sojourn = r.finished - r.arrived
@@ -533,49 +601,65 @@ class ServeLoop:
         self._slot_rid[s] = None
         heapq.heappush(self._free_slots, s)
 
+    def _leave(self, s: int, r: Request) -> None:
+        """Slot ``s``'s request ``r`` stops decoding: its positions leave
+        the running count."""
+        self._kv_tokens -= int(self._slot_base[s]) + len(r.tokens)
+
     def _admit(self, r: Request) -> None:
         r.submitted = self.now()
-        if self.mode == "arena" and r.session_id >= 0 and r.session_id in self._session_slot:
-            # cache hit: the session's slot is parked here from its previous
-            # turn — reclaim it and keep decoding from the resident cache,
-            # skipping the whole re-prefill dispatch. The slot's last token
-            # is still in _slot_last, so the decode step continues exactly
-            # where the prior turn left off.
-            s = self._session_slot.pop(r.session_id)
-            self._slot_rid[s] = r.rid
-            self._prefill_skipped += 1
-            return
-        logits, cache = self.prefill(
-            self.params, np.asarray(r.prompt[None], np.int32)
-        )
-        tok = int(jnp.argmax(logits[0, -1]))
-        r.tokens.append(tok)
-        r.first_token = self.now()
-        if self.mode == "arena":
-            # join at a token boundary: claim the lowest free slot, index-
-            # write the prefilled cache in — no regroup, no recompile
-            if self._arena is None:
-                self._arena = self._new_arena()
-            if not self._free_slots and self._session_slot:
-                # slot pressure: evict the least-recently-parked session —
-                # a live decode always outranks a speculative future turn
-                old_sid = next(iter(self._session_slot))
-                self._release_slot(self._session_slot.pop(old_sid))
-                self._sessions_evicted += 1
-            s = heapq.heappop(self._free_slots)
-            self._slot_rid[s] = r.rid
-            self._slot_last[s] = tok
-            self._arena = self._write_slot(self._arena, cache, s)
-            return
+        hit = self.mode == "arena" and r.session_id >= 0 and r.session_id in self._session_slot
         pos = int(r.prompt.shape[0])
-        if self.mode == "cohort":
-            for g in self._groups:
-                if g.pos == pos and len(g.rids) < self.batch:
-                    g.cache = _cat(g.cache, cache)
-                    g.rids.append(r.rid)
-                    g.last.append(tok)
-                    return
-        self._groups.append(_Group(pos, [r.rid], cache, [tok]))
+        with self._phase("serve.admit", tokens=0 if hit else pos):
+            if hit:
+                # cache hit: the session's slot is parked here from its
+                # previous turn — reclaim it and keep decoding from the
+                # resident cache, skipping the whole re-prefill dispatch. The
+                # slot's last token is still in _slot_last, so the decode
+                # step continues exactly where the prior turn left off.
+                s = self._session_slot.pop(r.session_id)
+                self._slot_rid[s] = r.rid
+                self._kv_tokens += int(self._slot_base[s])
+                self._prefill_skipped += 1
+                return
+            with self._inner_phase("serve.prefill"):
+                logits, cache = self.prefill(
+                    self.params, np.asarray(r.prompt[None], np.int32)
+                )
+            with self._inner_phase("serve.first_token"):
+                tok = int(jnp.argmax(logits[0, -1]))
+            r.tokens.append(tok)
+            r.first_token = self.now()
+            if self.mode == "arena":
+                # join at a token boundary: claim the lowest free slot,
+                # index-write the prefilled cache in — no regroup, no
+                # recompile
+                if self._arena is None:
+                    self._arena = self._new_arena()
+                if not self._free_slots and self._session_slot:
+                    # slot pressure: evict the least-recently-parked
+                    # session — a live decode always outranks a
+                    # speculative future turn
+                    old_sid = next(iter(self._session_slot))
+                    self._release_slot(self._session_slot.pop(old_sid))
+                    self._sessions_evicted += 1
+                s = heapq.heappop(self._free_slots)
+                self._slot_rid[s] = r.rid
+                self._slot_last[s] = tok
+                # the cache holds the prompt; the first token is fed next step
+                self._slot_base[s] = pos - 1
+                self._kv_tokens += pos
+                with self._phase("serve.slot_write"):
+                    self._arena = self._write_slot(self._arena, cache, s)
+                return
+            if self.mode == "cohort":
+                for g in self._groups:
+                    if g.pos == pos and len(g.rids) < self.batch:
+                        g.cache = _cat(g.cache, cache)
+                        g.rids.append(r.rid)
+                        g.last.append(tok)
+                        return
+            self._groups.append(_Group(pos, [r.rid], cache, [tok]))
 
     def _fill_slots(self) -> None:
         while self._ready and self._active_count() < self.batch:
@@ -596,17 +680,27 @@ class ServeLoop:
             head.last += g.last
             self._groups.remove(g)
 
-    def _step_arena(self) -> None:
+    def _step_arena(self) -> np.ndarray:
         """One decode step for the whole arena: a single dispatch advances
-        every occupied slot, whatever mix of positions they sit at."""
-        act = np.array([rid is not None for rid in self._slot_rid])
-        toks = self._slot_last[:, None].astype(np.int32)
-        new_toks, self._arena = self._decode_arena(
-            self.params, self._arena, toks, act
-        )
+        every occupied slot, whatever mix of positions they sit at. Returns
+        the new token of every slot."""
+        with self._phase("serve.decode") as span:
+            act = np.array([rid is not None for rid in self._slot_rid])
+            rows = int(act.sum())
+            self._kv_tokens += rows  # each active row writes one position
+            span.note(rows=rows, kv_tokens=self._kv_tokens)
+            toks = self._slot_last[:, None].astype(np.int32)
+            new_toks, self._arena = self._decode_arena(
+                self.params, self._arena, toks, act
+            )
         self._decode_calls += 1
-        self._occ_sum += int(act.sum())
-        new = np.asarray(new_toks)
+        self._occ_sum += rows
+        with self._phase("serve.decode.sync"):
+            return np.asarray(new_toks)
+
+    def _emit_arena(self, new: np.ndarray) -> None:
+        """Hand each active slot its new token; finish, park or free the
+        slots whose requests are done."""
         t_step = self.now()
         for s, rid in enumerate(list(self._slot_rid)):
             if rid is None:
@@ -623,10 +717,13 @@ class ServeLoop:
             if len(r.tokens) >= r.max_new:
                 r.finished = t_step
                 self._on_done(r)
+                self._leave(s, r)
                 if r.session_id >= 0 and not r.session_end:
                     # park: the session has more turns coming — keep the
                     # cache resident so the follow-up can skip re-prefill
+                    # (the next turn's tokens count on from what it holds)
                     self._slot_rid[s] = None
+                    self._slot_base[s] += len(r.tokens)
                     old = self._session_slot.pop(r.session_id, None)
                     if old is not None and old != s:
                         self._release_slot(old)
@@ -670,9 +767,15 @@ class ServeLoop:
     def _step(self) -> None:
         t_in, toks_in = time.perf_counter(), self._decode_tokens
         if self.mode == "arena":
-            self._step_arena()
+            new = self._step_arena()
+            with self._phase("serve.emit"):
+                self._emit_arena(new)
+                self._measure_rate(t_in, toks_in)
         else:
             self._step_groups()
+            self._measure_rate(t_in, toks_in)
+
+    def _measure_rate(self, t_in: float, toks_in: int) -> None:
         inst = (self._decode_tokens - toks_in) / max(
             time.perf_counter() - t_in, 1e-9
         )
@@ -692,7 +795,21 @@ class ServeLoop:
 
         Returns ``"step"`` (made progress), ``"wait"`` (deferred requests
         exist but the policy released nothing — the caller owns the
-        wall-clock and decides whether to sleep), or ``"done"``."""
+        wall-clock and decides whether to sleep), or ``"done"``.
+
+        The tick is a ``serve.tick`` span, and the longest one of the
+        session is kept with the own seconds of each phase inside it
+        (``stats()["slowest_tick"]``)."""
+        self._split = {}
+        with jax.profiler.TraceAnnotation("serve.tick"):
+            t = time.perf_counter()
+            status = self._tick()
+            s = time.perf_counter() - t
+        if self._slowest is None or s > self._slowest["s"]:
+            self._slowest = {"at_s": t - self._t0, "s": s, "phases": self._split}
+        return status
+
+    def _tick(self) -> str:
         if self._active_count() == 0:
             if self._ready:
                 self._fill_slots()
@@ -744,6 +861,10 @@ class ServeLoop:
             # and parked sessions LRU-evicted under slot pressure
             "prefill_skipped": self._prefill_skipped,
             "sessions_evicted": self._sessions_evicted,
+            # the longest tick: its start on now()'s clock, its seconds, and
+            # the own seconds of each phase inside it (the rest is the
+            # tick's own code); None before the first tick
+            "slowest_tick": self._slowest,
             "tokens_per_s": sum(len(r.tokens) for r in done) / wall if wall else 0.0,
             "mean_ttft_s": float(np.mean([r.first_token - r.arrived for r in done])) if done else -1,
             "mean_latency_s": float(np.mean([r.finished - r.arrived for r in done])) if done else -1,
@@ -804,6 +925,10 @@ def main(argv=None) -> dict:
         admission=args.admission, batched=not args.no_batch, mode=args.mode,
     )
     stats = loop.run_requests(reqs)
+    slow = stats["slowest_tick"]
+    phases = ", ".join(
+        f"{k} {v * 1e3:.1f}" for k, v in sorted(slow["phases"].items(), key=lambda kv: -kv[1])
+    )
     print(
         f"served {stats['completed']}/{args.requests} requests "
         f"(rejected {stats['rejected']}, admission={stats['admission']}, "
@@ -811,7 +936,8 @@ def main(argv=None) -> dict:
         f"{stats['tokens_per_s']:.1f} tok/s in {stats['decode_calls']} decode calls "
         f"(occupancy {stats['slot_occupancy']:.2f})  "
         f"ttft={stats['mean_ttft_s']*1e3:.0f}ms  "
-        f"latency={stats['mean_latency_s']*1e3:.0f}ms"
+        f"latency={stats['mean_latency_s']*1e3:.0f}ms  "
+        f"slowest tick {slow['s'] * 1e3:.1f}ms at {slow['at_s']:.3f}s ({phases} ms)"
     )
     return stats
 

@@ -175,7 +175,7 @@ def serve_phase(cfg, run, params, *, batch: int, max_len: int, prompt_lens, gen:
     compile_s = time.perf_counter() - t
     prompt = np.asarray(reqs[-1].prompt[None], np.int32)
     t = time.perf_counter()
-    jax.block_until_ready(loop.prefill(params, prompt))
+    jax.block_until_ready(loop.prefill(loop.params, prompt))
     prefill_s = time.perf_counter() - t
 
     stats = loop.run_requests(reqs)
@@ -185,7 +185,7 @@ def serve_phase(cfg, run, params, *, batch: int, max_len: int, prompt_lens, gen:
     if resolve_decode_impl(run.decode_attention_impl) == "kernel":
         # the served decode step itself must carry the kernel
         hlo = loop._decode_arena.lower(
-            params, loop._arena, np.zeros((batch, 1), np.int32), np.ones(batch, bool)
+            loop.params, loop._arena, np.zeros((batch, 1), np.int32), np.ones(batch, bool)
         ).as_text()
         check("tpu_custom_call" in hlo, "the arena decode step does not call the kernel")
         print("check served decode step: calls the Pallas kernel (tpu_custom_call)")

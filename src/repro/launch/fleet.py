@@ -93,6 +93,7 @@ from repro.core.router import (
     service_estimate_s,
 )
 from repro.launch.serve import Request, ServeLoop
+from repro.models import model as M
 
 
 class FleetLoop:
@@ -775,9 +776,10 @@ def build_fleet(
     """N identical ``ServeLoop`` replicas behind one :class:`FleetLoop`.
 
     Replica *i* lives on ``jax.devices()[i % n]``: its params are placed
-    there (one copy per device, shared by the replicas on it), and its
-    arena and steps follow them. The factory counts every replica it
-    builds, so an autoscaler spawn continues the same round-robin.
+    there as ``M.serving_params`` casts them (one copy per device, shared
+    by the replicas on it), and its arena and steps follow them. The
+    factory counts every replica it builds, so an autoscaler spawn
+    continues the same round-robin.
     Replica-level admission is ``None`` by construction: the fleet door is
     the only place a request is judged (the same no-private-path rule the
     admission layer enforces single-replica). The ``replica_factory``
@@ -795,7 +797,7 @@ def build_fleet(
     def factory():
         dev = devices[next(built) % len(devices)]
         if dev not in placed:
-            placed[dev] = jax.device_put(params, dev)
+            placed[dev] = M.serving_params(cfg, jax.device_put(params, dev))
         return ServeLoop(
             cfg, run, placed[dev], batch=batch, max_len=max_len,
             admission=None, batched=batched, mode=mode,
@@ -813,7 +815,6 @@ def main(argv=None) -> dict:
     from repro.configs.base import RunConfig
     from repro.data.dataset import SyntheticCorpus
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.models import model as M
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b-smoke")

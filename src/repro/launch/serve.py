@@ -27,6 +27,11 @@ fused into the jitted decode call, so the host round-trip per step is
 ``decode_calls`` (== steps taken) and ``slot_occupancy`` (mean active
 fraction per call) so a run shows exactly how much batching it got.
 
+**Weights** are held as ``models.model.serving_params`` makes them from
+the tree the loop is given: every matrix cast once to the compute dtype,
+the vectors as they were. The step programs then convert no weight in
+any call; ``stats()["served_param_bytes"]`` says what they read.
+
 Two legacy modes remain selectable: ``mode="cohort"`` is the PR-3
 position-grouped path (uniform lengths batch well; mixed lengths degrade
 toward per-slot dispatch — the regime claim 14 in
@@ -189,6 +194,13 @@ def _slot_write(arena, one, slot):
     return {"pos": pos, "layers": layers}
 
 
+def _tree_bytes(tree) -> dict[str, int]:
+    out: dict[str, int] = {}
+    for x in jax.tree.leaves(tree):
+        out[x.dtype.name] = out.get(x.dtype.name, 0) + int(x.nbytes)
+    return out
+
+
 class _Phase:
     """One phase of a tick (or of ``start()``'s first admissions). It opens
     a ``jax.profiler.TraceAnnotation`` (a host span on the device trace's
@@ -247,7 +259,11 @@ class ServeLoop:
         warmup: bool = True,
         mode: Optional[str] = None,
     ):
-        self.cfg, self.run, self.params = cfg, run, params
+        self.cfg, self.run = cfg, run
+        # the step programs read matrices in the compute dtype: cast once
+        # here, and hold no reference to the float32 tree (the caller may
+        # free it), rather than convert every matrix in every call
+        self.params = M.serving_params(cfg, params)
         self.batch = batch
         self.max_len = max_len
         self.admission = admission
@@ -865,6 +881,9 @@ class ServeLoop:
             # the own seconds of each phase inside it (the rest is the
             # tick's own code); None before the first tick
             "slowest_tick": self._slowest,
+            # {dtype name: bytes} of the parameter tree the step programs
+            # read: the matrices in the compute dtype, the vectors as given
+            "served_param_bytes": _tree_bytes(self.params),
             "tokens_per_s": sum(len(r.tokens) for r in done) / wall if wall else 0.0,
             "mean_ttft_s": float(np.mean([r.first_token - r.arrived for r in done])) if done else -1,
             "mean_latency_s": float(np.mean([r.finished - r.arrived for r in done])) if done else -1,

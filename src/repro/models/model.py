@@ -93,6 +93,29 @@ def model_defs(cfg: ModelConfig) -> dict:
     return defs
 
 
+def serving_params(cfg: ModelConfig, params: Optional[dict]) -> Optional[dict]:
+    """The tree the inference step programs read: every matrix leaf cast
+    once to ``cfg.compute_dtype``, every other leaf as it was.
+
+    A matrix is a leaf of rank ≥ 2 once the stacked period axis of
+    ``layers`` is set aside. The model rounds each matrix to the compute
+    dtype where it reads it, so a jitted step handed float32 matrices
+    converts all of them again in every call; handed this tree, its
+    matmuls read the same bits and the converts drop out. The vectors
+    keep their dtype: the norm gains, qk-norm and the SSM's
+    ``a_log``/``dt_bias`` are read in float32, and the rest (``d_skip``,
+    gate biases) are a few hundred bytes a layer. ``None`` passes through.
+    """
+    if params is None:
+        return None
+    dt = jnp.dtype(cfg.compute_dtype)
+
+    def cast(tree, stacked: int):
+        return jax.tree.map(lambda x: x.astype(dt) if x.ndim - stacked >= 2 else x, tree)
+
+    return {k: cast(v, int(k == "layers")) for k, v in params.items()}
+
+
 def init_model(key: jax.Array, cfg: ModelConfig) -> dict:
     return build_params(model_defs(cfg), key)
 

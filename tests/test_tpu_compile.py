@@ -95,7 +95,8 @@ def test_arena_decode_step_compiles_and_fits_one_v5e(one_chip):
     (the platform's choice on a TPU) inside, within one chip's 16 GB."""
     run = RunConfig(remat="none", attention_impl="xla", decode_attention_impl="kernel")
     put = lambda tree: jax.tree.map(lambda x: _on(one_chip, x.shape, x.dtype), tree)
-    params = put(M.model_shapes(QWEN3))
+    # the tree ServeLoop serves: matrices in bf16, cast when it is built
+    params = put(jax.eval_shape(lambda p: M.serving_params(QWEN3, p), M.model_shapes(QWEN3)))
     arena = put(jax.eval_shape(lambda: M.init_cache(QWEN3, BATCH, 1024)))
 
     def arena_decode(p, c, toks, act):

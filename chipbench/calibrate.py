@@ -29,7 +29,7 @@ class Calibration:
         self.cell, self.loop = cell, None
 
     def read(self, seed: int, seconds: float) -> dict:
-        from chipbench.system import build_loop
+        from chipbench.system import build_loop, serving_params
         from chipbench.traffic import Traffic, prompt_buckets
         from chipbench.weights import make_weights
 
@@ -39,7 +39,8 @@ class Calibration:
         if self.loop is None:
             self.loop = build_loop(c, params, cs["arena"]["batch"], max_len)
             run.warm(self.loop, prompt_buckets(mix))
-        self.loop.params = params
+        else:  # the tree the cells' programs read, as ServeLoop casts it when built
+            self.loop.params = serving_params(self.loop.cfg, params)
         del params
         traffic = Traffic(mix, c["vocab_size"], seed, rate=cs.get("rate_per_s"), seconds=seconds)
         recs = driver.drive(self.loop, traffic, seconds, clients=cs.get("clients"))

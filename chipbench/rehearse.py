@@ -5,9 +5,11 @@ each holds in device memory. Nothing runs; no chip is needed.
 
 For every cell (default: all): the arena decode step at the cell's batch
 and length with the Pallas decode kernel, the prefill of its longest
-prompt bucket, the slot write, the weight maker and the reference's gap
-program. ``memory_analysis`` counts one program at a time; the decode
-step's arguments are the parameters and the arena.
+prompt bucket and the slot write, all on the tree ``ServeLoop`` serves
+(``serving_params`` of the weights' shapes); the weight maker, with the
+largest leaf it makes; and the reference's gap program on the weights as
+made. ``memory_analysis`` counts one program at a time; the decode step's
+arguments are the served parameters and the arena.
 """
 
 import json
@@ -24,7 +26,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from chipbench import reference, spec  # noqa: E402
-from chipbench.system import ServeLoop, model_config  # noqa: E402
+from chipbench.system import ServeLoop, model_config, serving_params  # noqa: E402
 from chipbench.traffic import prompt_buckets  # noqa: E402
 from chipbench.weights import _make, maker  # noqa: E402
 
@@ -44,7 +46,9 @@ def main(names) -> None:
         cfg = model_config(c)
         run = RunConfig(remat="none", attention_impl="xla", decode_attention_impl="kernel")
         loop = ServeLoop(cfg, run, None, batch=batch, max_len=max_len, mode="arena")
-        params = on(jax.eval_shape(lambda: _make(c, jax.random.PRNGKey(0))))
+        made = on(jax.eval_shape(lambda: _make(c, jax.random.PRNGKey(0))))
+        params = on(jax.eval_shape(lambda p: serving_params(cfg, p), made))
+        largest = max(x.size * x.dtype.itemsize for x in jax.tree.leaves(made))
         arena = on(jax.eval_shape(lambda: M.init_cache(cfg, batch, max_len)))
         longest = max(prompt_buckets(cell.mix))
         one = on(jax.eval_shape(lambda: M.init_cache(cfg, 1, max_len)))
@@ -57,7 +61,7 @@ def main(names) -> None:
             "weights": maker(json.dumps(c, sort_keys=True)).lower(
                 jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)),
             "reference": reference.gap_fn(json.dumps(c, sort_keys=True), False).lower(
-                params, i32(max_len), i32(max_len)),
+                made, i32(max_len), i32(max_len)),
         }
         for prog, lowered in progs.items():
             compiled = lowered.compile()
@@ -66,7 +70,8 @@ def main(names) -> None:
             print(f"{name} {prog}: arguments {m.argument_size_in_bytes / 1e9:.3f} GB, "
                   f"outputs {m.output_size_in_bytes / 1e9:.3f} GB, temporaries "
                   f"{m.temp_size_in_bytes / 1e9:.3f} GB, aliased {m.alias_size_in_bytes / 1e9:.3f} GB"
-                  + ("" if kernel is None else f", Pallas kernel in the step: {kernel}"), flush=True)
+                  + ("" if kernel is None else f", Pallas kernel in the step: {kernel}")
+                  + (f", largest leaf {largest / 1e9:.3f} GB" if prog == "weights" else ""), flush=True)
 
 
 if __name__ == "__main__":

@@ -54,6 +54,8 @@ class Context:
     trace: object = None
     prefill_calls: list = field(default_factory=list)  # prompt lengths
     decode_calls: list = field(default_factory=list)  # valid positions of active rows
+    stats: dict = field(default_factory=dict)  # loop.stats() at the close
+    spans: list = field(default_factory=list)  # the program's serve.* spans in the window (trace.Span)
 
 
 def require_chip(jax, chips: int) -> dict:
@@ -187,9 +189,11 @@ def main(argv=None, root: Path = spec.ROOT, chip_check=require_chip) -> dict:
     device = dict(dev, memory_peak_bytes=max(
         int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.devices()))
     lateness = [r.sent - r.due for r in records] or [0.0]
+    slowest = stats["slowest_tick"] or {"s": 0.0, "at_s": 0.0}
     print(f"window: {len(records)} requests sent, {stats['completed']} completed, "
           f"{stats['decode_calls']} decode calls, occupancy {stats['slot_occupancy']:.3f}, "
-          f"generator late by at most {max(lateness) * 1e3:.1f} ms; "
+          f"generator late by at most {max(lateness) * 1e3:.1f} ms, slowest tick "
+          f"{slowest['s'] * 1e3:.1f} ms at {slowest['at_s']:.1f} s; "
           f"compiles in the window: {in_window}", file=sys.stderr)
 
     close = args.seconds
@@ -200,7 +204,8 @@ def main(argv=None, root: Path = spec.ROOT, chip_check=require_chip) -> dict:
         tr = trace.load(trace.find_xplane(tracer.dir))
         ctx = Context(c, records, close, peak, tr,
                       [n for t, n in tracer.prefill_calls if t >= tracer.t0],
-                      [v for t, v in tracer.decode_calls if t >= tracer.t0])
+                      [v for t, v in tracer.decode_calls if t >= tracer.t0],
+                      stats, trace.program_spans(tr))
         for name, mod in spec.load_metrics(root).items():
             value = mod.read(ctx) if wanted is None or name in wanted else None
             if value is not None:

@@ -1,10 +1,12 @@
 """The system under test: ``repro.launch.serve.ServeLoop`` in arena mode.
 
 The only module of the benchmark that imports the program. It turns a
-configuration file into the program's ``ModelConfig`` and builds the
-replica the window drives: admission ``admit_all``, greedy decoding fused
-into the arena step, decode attention ``auto`` (the Pallas kernel on a
-TPU), prefill attention ``xla`` as ``repro.launch.serve.main`` sets it.
+configuration file into the program's ``ModelConfig`` (by the
+configuration's architecture module, which imports it from here) and
+builds the replica the window drives: admission ``admit_all``, greedy
+decoding fused into the arena step, decode attention ``auto`` (the Pallas
+kernel on a TPU), prefill attention ``xla`` as ``repro.launch.serve.main``
+sets it.
 """
 
 from __future__ import annotations
@@ -16,33 +18,19 @@ SRC = Path(__file__).resolve().parents[1] / "src"
 if str(SRC) not in sys.path:
     sys.path.insert(0, str(SRC))
 
+from chipbench import spec  # noqa: E402
 from repro.configs.base import ModelConfig, RunConfig  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve import Request, ServeLoop  # noqa: E402
+from repro.models.model import serving_params  # noqa: E402
 
-__all__ = ["Request", "ServeLoop", "build_loop", "enable_compile_cache", "model_config"]
+__all__ = ["Request", "ServeLoop", "build_loop", "enable_compile_cache", "model_config", "serving_params"]
 
 
 def model_config(c: dict) -> "ModelConfig":
-    """The program's ``ModelConfig`` for a configuration file."""
-    cfg = ModelConfig(
-        name=c["name"],
-        family="dense",
-        num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim", 0),
-        d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"],
-        qk_norm=c["qk_norm"],
-        rope_theta=float(c["rope_theta"]),
-        norm_eps=c["rms_norm_eps"],
-        tie_embeddings=c["tie_word_embeddings"],
-        param_dtype=c["param_dtype"],
-        compute_dtype=c["compute_dtype"],
-        source=c["source"],
-    )
+    """The program's ``ModelConfig`` for a configuration file, as its
+    architecture module (``arch/<model_type>.py``) states it."""
+    cfg = spec.arch(c).model_config(c)
     cfg.validate()
     return cfg
 

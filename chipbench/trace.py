@@ -3,8 +3,9 @@ device time per program and per operation.
 
 A trace is read once into plain intervals (seconds on the trace's clock):
 the device's operations and programs (lines ``XLA Ops`` and ``XLA
-Modules`` of each ``/device:TPU:<n>`` plane) and the harness's own host
-spans (``chipbench.*`` profiler annotations). ``chipbench.window`` marks
+Modules`` of each ``/device:TPU:<n>`` plane), the harness's own host
+spans (``chipbench.*`` profiler annotations) and, apart from those, the
+program's (``serve.*``, with their arguments). ``chipbench.window`` marks
 the traced part of the measured window; everything is clipped to it.
 ``layers.json`` maps program and operation names to the layers that the
 per-layer metrics read.
@@ -18,12 +19,22 @@ import json
 import math
 import os
 import re
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
+from typing import NamedTuple
 
 LAYERS = Path(__file__).resolve().parent / "layers.json"
 
 Interval = tuple[str, float, float]  # (name, start_s, end_s)
+
+
+class Span(NamedTuple):
+    """A host span the program wrote, with the arguments it gave."""
+
+    name: str
+    start: float
+    end: float
+    args: dict
 
 
 @dataclass
@@ -32,6 +43,7 @@ class Trace:
     modules: dict[str, list[Interval]]  # device plane name -> programs
     spans: list[Interval]  # the harness's host spans
     window: tuple[float, float]
+    program: list[Span] = field(default_factory=list)  # the program's serve.* host spans
 
     @property
     def window_s(self) -> float:
@@ -50,7 +62,7 @@ def load(path: str) -> Trace:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    ops, modules, spans = {}, {}, []
+    ops, modules, spans, program = {}, {}, [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:") and "Core" not in plane.name:
             for line in plane.lines:
@@ -59,12 +71,15 @@ def load(path: str) -> Trace:
                     (ops if line.name == "XLA Ops" else modules)[plane.name] = evs
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans += [(e.name, e.start_ns * 1e-9, e.end_ns * 1e-9)
-                          for e in line.events if e.name.startswith("chipbench.")]
+                for e in line.events:
+                    if e.name.startswith("chipbench."):
+                        spans.append((e.name, e.start_ns * 1e-9, e.end_ns * 1e-9))
+                    elif e.name.startswith("serve."):
+                        program.append(Span(e.name, e.start_ns * 1e-9, e.end_ns * 1e-9, dict(e.stats)))
     windows = [s for s in spans if s[0] == "chipbench.window"]
     if not windows:
         raise ValueError("the trace holds no chipbench.window span")
-    return Trace(ops, modules, spans, (windows[0][1], windows[0][2]))
+    return Trace(ops, modules, spans, (windows[0][1], windows[0][2]), program)
 
 
 def clip(intervals, lo: float, hi: float) -> list[Interval]:
@@ -113,6 +128,14 @@ def busy_s(trace: Trace) -> float:
     where the trace holds no device)."""
     planes = sorted(trace.modules)
     return sum(measure(busy(trace, p)) for p in planes) / len(planes) if planes else 0.0
+
+
+def program_spans(trace: Trace) -> list[Span]:
+    """The program's spans that overlap the window, clipped to it, in order
+    of their start."""
+    lo, hi = trace.window
+    return sorted((s._replace(start=max(s.start, lo), end=min(s.end, hi))
+                   for s in trace.program if s.end > lo and s.start < hi), key=lambda s: s.start)
 
 
 def spans_named(trace: Trace, name: str) -> list[Interval]:

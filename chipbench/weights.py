@@ -1,10 +1,12 @@
 """Random weights from ``--seed``, made by the benchmark on the device.
 
-One jitted call makes every array, in float32 (the type the program serves
-its parameters in), laid out as the program's parameter tree: the
-program takes them as input and the reference makes them again, from the
-same seed, after the program's state is freed. So the reference takes no
-weights that the program made.
+One jitted call makes every array, in the configuration's
+``param_dtype``, laid out as the program's parameter tree (the
+architecture module's ``layout``): the program takes them as input and
+the reference makes them again, from the same seed, after the program's
+state is freed. So the reference takes no weights that the program made.
+Each leaf is drawn directly in that dtype, so a bfloat16 tree needs no
+float32 copy, and a float32 tree is what it always was.
 
 Scales: matrices draw N(0, 1/fan_in) over their contracted dimensions, the
 embedding N(0, 0.02²) (the source's ``initializer_range``), and RMSNorm
@@ -21,47 +23,14 @@ import math
 import jax
 import jax.numpy as jnp
 
+from chipbench import spec
+
 EMBED_STD = 0.02
 NORM_STD = 0.1
 
 
-def dims(c: dict) -> dict:
-    """The sizes of a configuration file, under short names."""
-    return {
-        "d": c["hidden_size"], "L": c["num_hidden_layers"], "H": c["num_attention_heads"],
-        "KH": c["num_key_value_heads"], "hd": c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"],
-        "ff": c["intermediate_size"], "V": c["vocab_size"],
-    }
-
-
-def layout(c: dict) -> dict:
-    """``{path: (shape, std, fan_in_axes)}`` of every parameter, where
-    ``fan_in_axes`` are the contracted axes (None for an embedding or gain)."""
-    z = dims(c)
-    d, L, H, KH, hd, ff, V = (z[k] for k in ("d", "L", "H", "KH", "hd", "ff", "V"))
-    out = {
-        ("embed",): ((V, d), EMBED_STD, None),
-        ("final_norm",): ((d,), NORM_STD, None),
-        ("layers", "b0", "norm"): ((L, d), NORM_STD, None),
-        ("layers", "b0", "attn", "wq"): ((L, d, H, hd), None, (1,)),
-        ("layers", "b0", "attn", "wk"): ((L, d, KH, hd), None, (1,)),
-        ("layers", "b0", "attn", "wv"): ((L, d, KH, hd), None, (1,)),
-        ("layers", "b0", "attn", "wo"): ((L, H, hd, d), None, (1, 2)),
-        ("layers", "b0", "ffn_norm"): ((L, d), NORM_STD, None),
-        ("layers", "b0", "ffn", "gate"): ((L, d, ff), None, (1,)),
-        ("layers", "b0", "ffn", "up"): ((L, d, ff), None, (1,)),
-        ("layers", "b0", "ffn", "down"): ((L, ff, d), None, (1,)),
-    }
-    if c["qk_norm"]:
-        out[("layers", "b0", "attn", "q_norm")] = ((L, hd), NORM_STD, None)
-        out[("layers", "b0", "attn", "k_norm")] = ((L, hd), NORM_STD, None)
-    if not c["tie_word_embeddings"]:
-        out[("lm_head",)] = ((d, V), None, (0,))
-    return out
-
-
 def param_count(c: dict) -> int:
-    return sum(math.prod(shape) for shape, _, _ in layout(c).values())
+    return spec.arch(c).param_count(c)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -73,14 +42,15 @@ def seed_key(seed: int) -> jax.Array:
 
 def _make(c: dict, key: jax.Array) -> dict:
     tree: dict = {}
-    items = sorted(layout(c).items())
+    dtype = jnp.dtype(c["param_dtype"])
+    items = sorted(spec.arch(c).layout(c).items())
     for k, (path, (shape, std, fan_in)) in zip(jax.random.split(key, len(items)), items):
         if std is None:
             std = 1.0 / math.sqrt(math.prod(shape[a] for a in fan_in))
         node = tree
         for p in path[:-1]:
             node = node.setdefault(p, {})
-        node[path[-1]] = std * jax.random.normal(k, shape, jnp.float32)
+        node[path[-1]] = std * jax.random.normal(k, shape, dtype)
     return tree
 
 

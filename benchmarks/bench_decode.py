@@ -120,7 +120,7 @@ def _roofline_fraction(cfg, run, params, max_len: int) -> dict:
     arena = M.init_cache(cfg, BATCH, max_len)
     toks = jnp.zeros((BATCH, 1), jnp.int32)
     act = jnp.ones((BATCH,), bool)
-    loop._decode_arena(loop.params, arena, toks, act)  # compile
+    _, arena = loop._decode_arena(loop.params, arena, toks, act)  # compile; consumes the arena
     reps = 20
     t0 = time.perf_counter()
     for _ in range(reps):

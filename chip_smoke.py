@@ -182,6 +182,8 @@ def serve_phase(cfg, run, params, *, batch: int, max_len: int, prompt_lens, gen:
     _check_served(reqs, stats, n_requests, gen)
     check(stats["decode_calls"] < stats["decode_steps"],
           f"no batching: {stats['decode_calls']} calls for {stats['decode_steps']} steps")
+    check(stats["arena_in_place"] == 1.0,
+          f"the arena was not donated to every call: {stats['arena_in_place']}")
     if resolve_decode_impl(run.decode_attention_impl) == "kernel":
         # the served decode step itself must carry the kernel
         hlo = loop._decode_arena.lower(
@@ -190,7 +192,8 @@ def serve_phase(cfg, run, params, *, batch: int, max_len: int, prompt_lens, gen:
         check("tpu_custom_call" in hlo, "the arena decode step does not call the kernel")
         print("check served decode step: calls the Pallas kernel (tpu_custom_call)")
     print(f"serve: {stats['completed']}/{n_requests} requests, {stats['decode_steps']} decode "
-          f"steps in {stats['decode_calls']} calls, occupancy {stats['slot_occupancy']:.3f}")
+          f"steps in {stats['decode_calls']} calls, occupancy {stats['slot_occupancy']:.3f}, "
+          f"arena in place {stats['arena_in_place']:.2f}")
     print(f"smoke timing (one cold run, not a benchmark): compile+warm {compile_s:.2f} s, "
           f"prefill {prompt.shape[1]} tokens {prefill_s * 1e3:.1f} ms, "
           f"served {stats['tokens_per_s']:.1f} tok/s over {stats['wall_s']:.2f} s, "

@@ -25,7 +25,13 @@ marking the slot free at a token boundary. Greedy sampling (argmax) is
 fused into the jitted decode call, so the host round-trip per step is
 ``batch`` token ids, not a logits tensor. ``stats()`` reports
 ``decode_calls`` (== steps taken) and ``slot_occupancy`` (mean active
-fraction per call) so a run shows exactly how much batching it got.
+fraction per call) so a run shows exactly how much batching it got. The
+decode step and the slot write are handed the arena to consume (donated),
+so each can update it in place: a step writes one position per active
+row, a join one slot. ``stats()["arena_in_place"]`` is the share of those
+calls whose arena was donated (consumed by the call); that the compiled
+programs then copy nothing is checked on their HLO, in
+``tests/test_tpu_compile.py``.
 
 **Weights** are held as ``models.model.serving_params`` makes them from
 the tree the loop is given: every matrix cast once to the compute dtype,
@@ -289,9 +295,12 @@ class ServeLoop:
             return jnp.argmax(logits[:, -1, :], axis=-1), new_cache
 
         # greedy sampling fused into the dispatch: the per-step host
-        # round-trip is `batch` token ids, not a (B, 1, vocab) logits pull
-        self._decode_arena = jax.jit(_arena_decode)
-        self._write_slot = jax.jit(_slot_write)
+        # round-trip is `batch` token ids, not a (B, 1, vocab) logits pull.
+        # Both arena programs are handed the arena to consume: the step
+        # writes one position a row and the slot write one slot, in place,
+        # where a kept input would cost a whole copy of the arena a call
+        self._decode_arena = jax.jit(_arena_decode, donate_argnums=1)
+        self._write_slot = jax.jit(_slot_write, donate_argnums=0)
 
     def _new_arena(self):
         """A zeroed arena on the params' device. The replica runs where its
@@ -387,6 +396,8 @@ class ServeLoop:
         self._done_hist: dict[int, list[float]] = {}  # sojourns per class
         self._decode_tokens = 0
         self._decode_calls = 0
+        self._arena_calls = 0  # decode steps and slot writes on the arena
+        self._arena_in_place = 0  # … of them whose arena was donated
         self._cancelled = 0
         self._offered = 0
         # measured decode throughput (tokens/s), EMA over per-step rates
@@ -617,6 +628,12 @@ class ServeLoop:
         self._slot_rid[s] = None
         heapq.heappush(self._free_slots, s)
 
+    def _count_arena_call(self, old) -> None:
+        """Count a decode step or slot write as donated when the call
+        consumed every buffer of the arena it was handed."""
+        self._arena_calls += 1
+        self._arena_in_place += all(x.is_deleted() for x in jax.tree.leaves(old))
+
     def _leave(self, s: int, r: Request) -> None:
         """Slot ``s``'s request ``r`` stops decoding: its positions leave
         the running count."""
@@ -666,7 +683,9 @@ class ServeLoop:
                 self._slot_base[s] = pos - 1
                 self._kv_tokens += pos
                 with self._phase("serve.slot_write"):
-                    self._arena = self._write_slot(self._arena, cache, s)
+                    old = self._arena
+                    self._arena = self._write_slot(old, cache, s)
+                    self._count_arena_call(old)
                 return
             if self.mode == "cohort":
                 for g in self._groups:
@@ -706,9 +725,9 @@ class ServeLoop:
             self._kv_tokens += rows  # each active row writes one position
             span.note(rows=rows, kv_tokens=self._kv_tokens)
             toks = self._slot_last[:, None].astype(np.int32)
-            new_toks, self._arena = self._decode_arena(
-                self.params, self._arena, toks, act
-            )
+            old = self._arena
+            new_toks, self._arena = self._decode_arena(self.params, old, toks, act)
+            self._count_arena_call(old)
         self._decode_calls += 1
         self._occ_sum += rows
         with self._phase("serve.decode.sync"):
@@ -872,6 +891,12 @@ class ServeLoop:
                 if self._decode_calls
                 else 0.0
             ),
+            # share of the arena's decode steps and slot writes whose
+            # arena was donated (consumed by the call); 1.0 unless
+            # donation failed. None where no call touched an arena
+            "arena_in_place": (
+                self._arena_in_place / self._arena_calls if self._arena_calls else None
+            ),
             "cancelled": self._cancelled,
             # session residency (PR 10): prefills skipped via a parked slot
             # and parked sessions LRU-evicted under slot pressure
@@ -948,6 +973,7 @@ def main(argv=None) -> dict:
     phases = ", ".join(
         f"{k} {v * 1e3:.1f}" for k, v in sorted(slow["phases"].items(), key=lambda kv: -kv[1])
     )
+    in_place = stats["arena_in_place"]
     print(
         f"served {stats['completed']}/{args.requests} requests "
         f"(rejected {stats['rejected']}, admission={stats['admission']}, "
@@ -956,7 +982,8 @@ def main(argv=None) -> dict:
         f"(occupancy {stats['slot_occupancy']:.2f})  "
         f"ttft={stats['mean_ttft_s']*1e3:.0f}ms  "
         f"latency={stats['mean_latency_s']*1e3:.0f}ms  "
-        f"slowest tick {slow['s'] * 1e3:.1f}ms at {slow['at_s']:.3f}s ({phases} ms)"
+        f"slowest tick {slow['s'] * 1e3:.1f}ms at {slow['at_s']:.3f}s ({phases} ms)  "
+        f"arena in place {'-' if in_place is None else f'{in_place:.2f}'}"
     )
     return stats
 

@@ -326,11 +326,31 @@ def resolve_decode_impl(impl: str) -> str:
     return impl
 
 
+def kv_write_form(cache_len: int, rules: Optional[ShardingRules]) -> str:
+    """How a decode step writes its new K/V into the cache.
+
+    ``"scatter"`` writes the one position of each row in place; on a
+    batch-sharded or replicated cache it stays local to each shard.
+    Where the rules shard the cache's sequence axis, ``"select"`` writes
+    through an elementwise mask (``iota == slot``) over the whole layer
+    instead, which is local to each sequence shard by construction (an
+    update at a traced position there has made GSPMD reshard the entire
+    cache: head-layout ⇄ seq-layout all-to-alls, ~cache-size bytes per
+    layer per token)."""
+    sharded = (
+        rules is not None
+        and rules.tp_size > 1
+        and rules.resolve("kv_seq", cache_len) is not None
+    )
+    return "select" if sharded else "scatter"
+
+
 def attn_apply_step(
     cfg: ModelConfig,
     run: RunConfig,
     params: dict,
     cache: dict,
+    layer: jax.Array,
     x: jax.Array,
     pos: jax.Array,
     rules: Optional[ShardingRules],
@@ -338,26 +358,39 @@ def attn_apply_step(
     """Single-token decode. x: (B, 1, D); pos: (B,) int32 — tokens so far
     *per slot*, so one dispatch serves a batch whose rows sit at different
     cache positions (the continuous-batching contract; a scalar pos
-    broadcasts for the uniform case)."""
+    broadcasts for the uniform case). A row with a negative ``pos`` is
+    inactive: its cache is left as it was.
+
+    ``cache`` holds K and V stacked over the periods, ``(L, B, cap, KH,
+    D)``; this layer's new K/V are written into layer ``layer`` of the
+    stack, which is returned (the caller carries it, so the write is in
+    place) with the attention's output."""
     dt = jnp.dtype(cfg.compute_dtype)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (x.shape[0],))
     positions = pos[:, None]  # (B, 1) — per-row RoPE phase
     q, k, v = _project_qkv(cfg, params, x, positions, rules)
 
-    cap = cache["k"].shape[1]
+    cap = cache["k"].shape[2]
     slot = pos % cap if cfg.sliding_window else jnp.minimum(pos, cap - 1)
-    # Elementwise masked write (iota == slot): shards cleanly along the
-    # seq-sharded cache dim. A dynamic_update_slice here makes GSPMD reshard
-    # the entire cache (head-layout ⇄ seq-layout all-to-alls, ~cache-size
-    # bytes per layer per token); the select keeps every shard local.
-    k = shard_constraint(k, rules, ("batch", None, None, None))
-    v = shard_constraint(v, rules, ("batch", None, None, None))
+    # an inactive row writes at `cap`, which no write form reaches: a -1
+    # would wrap to the last position (and `pos % cap` already maps it there)
+    wslot = jnp.where(pos >= 0, slot, cap)
+    k = shard_constraint(k, rules, ("batch", None, None, None)).astype(cache["k"].dtype)
+    v = shard_constraint(v, rules, ("batch", None, None, None)).astype(cache["v"].dtype)
     idx = jnp.arange(cap)
-    write = idx[None, :, None, None] == slot[:, None, None, None]
-    new_k = jnp.where(write, k.astype(cache["k"].dtype), cache["k"])
-    new_v = jnp.where(write, v.astype(cache["v"].dtype), cache["v"])
-    new_k = shard_constraint(new_k, rules, attn_cache_axes()["k"])
-    new_v = shard_constraint(new_v, rules, attn_cache_axes()["v"])
+    new, read = {}, {}
+    if kv_write_form(cap, rules) == "select":
+        write = idx[None, :, None, None] == wslot[:, None, None, None]
+        for name, x_new in (("k", k), ("v", v)):
+            old = jax.lax.dynamic_index_in_dim(cache[name], layer, 0, keepdims=False)
+            read[name] = shard_constraint(jnp.where(write, x_new, old), rules, attn_cache_axes()[name])
+            new[name] = jax.lax.dynamic_update_index_in_dim(cache[name], read[name], layer, 0)
+    else:
+        rows = jnp.arange(x.shape[0])
+        for name, x_new in (("k", k), ("v", v)):
+            new[name] = cache[name].at[layer, rows, wslot].set(x_new[:, 0], mode="drop")
+            read[name] = jax.lax.dynamic_index_in_dim(new[name], layer, 0, keepdims=False)
+    new_k, new_v = read["k"], read["v"]
 
     # validity, per row: slots < pos+1 filled (full cache: monotone; ring:
     # all once wrapped) — (B, cap), exactly the mask shape the flash-decode
@@ -390,4 +423,4 @@ def attn_apply_step(
     else:
         raise ValueError(f"unknown decode_attention_impl {impl!r}")
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dt))
-    return y, {"k": new_k, "v": new_v}
+    return y, new

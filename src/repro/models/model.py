@@ -255,27 +255,34 @@ def _mlstm_full_with_cache(cfg, run, params, x, rules):
     return out, {"state": h_final}
 
 
-def _apply_block_step(cfg, run, j, blk, cache_j, h, pos, rules):
+def _layer(tree, layer):
+    return jax.tree.map(lambda x: jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False), tree)
+
+
+def _set_layer(tree, layer, new):
+    return jax.tree.map(lambda x, n: jax.lax.dynamic_update_index_in_dim(x, n, layer, 0), tree, new)
+
+
+def _apply_block_step(cfg, run, j, blk, cache_j, layer, h, pos, rules):
+    """One block of one decode step. ``cache_j`` is the block's cache
+    stacked over the periods; ``layer`` the (traced) period index. The
+    block updates its layer of the stack in place and returns the stack:
+    attention writes one position per row, the small recurrent states are
+    written whole."""
     kind = cfg.layer_kind(j)
     eps = cfg.norm_eps
     new_cache: dict[str, Any] = {}
     if kind == "attn":
         hn = rms_norm(h, blk["norm"], eps)
-        y, c = attn.attn_apply_step(cfg, run, blk["attn"], cache_j["attn"], hn, pos, rules)
+        y, c = attn.attn_apply_step(cfg, run, blk["attn"], cache_j["attn"], layer, hn, pos, rules)
         new_cache["attn"] = c
         h = h + y
-    elif kind == "mamba":
-        hn = rms_norm(h, blk["norm"], eps)
-        y, c = ssm.mamba_apply_step(cfg, blk["mamba"], cache_j["mamba"], hn, rules)
-        new_cache["mamba"] = c
-        h = h + y
-    elif kind == "mlstm":
-        y, c = ssm.mlstm_apply_step(cfg, blk["mlstm"], cache_j["mlstm"], h, rules)
-        new_cache["mlstm"] = c
-        h = h + y
-    elif kind == "slstm":
-        y, c = ssm.slstm_apply_step(cfg, blk["slstm"], cache_j["slstm"], h, rules)
-        new_cache["slstm"] = c
+    else:
+        step = {"mamba": ssm.mamba_apply_step, "mlstm": ssm.mlstm_apply_step,
+                "slstm": ssm.slstm_apply_step}[kind]
+        x = rms_norm(h, blk["norm"], eps) if kind == "mamba" else h
+        y, c = step(cfg, blk[kind], _layer(cache_j[kind], layer), x, rules)
+        new_cache[kind] = _set_layer(cache_j[kind], layer, c)
         h = h + y
 
     if "moe" in blk:
@@ -414,17 +421,23 @@ def decode_step(
     step_pos = pos if active is None else jnp.where(active, pos, -1)
     p = cfg.period
 
-    def body(h, xs):
-        pparams, pcache = xs
-        new_caches = {}
+    # the stacked cache rides in the carry and each layer is updated in
+    # place: emitted as the scan's output instead, it would be rebuilt (and
+    # its input sliced) whole every step
+    def body(carry, xs):
+        h, caches = carry
+        pparams, layer = xs
+        caches = dict(caches)
         for j in range(p):
-            h, c = _apply_block_step(
-                cfg, run, j, pparams[f"b{j}"], pcache[f"b{j}"], h, step_pos, rules
+            h, caches[f"b{j}"] = _apply_block_step(
+                cfg, run, j, pparams[f"b{j}"], caches[f"b{j}"], layer, h, step_pos, rules
             )
-            new_caches[f"b{j}"] = c
-        return h, new_caches
+        return (h, caches), None
 
-    h, new_layer_caches = jax.lax.scan(body, h, (params["layers"], cache["layers"]), unroll=run.scan_unroll)
+    layers = jnp.arange(cfg.num_periods, dtype=jnp.int32)
+    (h, new_layer_caches), _ = jax.lax.scan(
+        body, (h, cache["layers"]), (params["layers"], layers), unroll=run.scan_unroll
+    )
     logits = _head(cfg, params, h, rules)
     new_pos = pos + 1 if active is None else pos + active.astype(jnp.int32)
     return logits, {"pos": new_pos, "layers": new_layer_caches}

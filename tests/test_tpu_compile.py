@@ -11,6 +11,7 @@ here cannot be read back without a chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.configs.base import RunConfig
 from repro.kernels import ops
+from repro.launch.serve import ServeLoop
 from repro.models import model as M
 
 QWEN3 = get_config("qwen3-1.7b")
@@ -111,3 +113,32 @@ def test_arena_decode_step_compiles_and_fits_one_v5e(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16e9, used
+
+
+# an instruction whose result is the stacked cache or one layer of it in the
+# arena's (B, S, KH, D) order, at the chat cell's width (16 x 1024)
+ARENA_SHAPED = re.compile(r"%([\w.\-]+) = \(?bf16\[(?:28,|1,)?16,1024,8,128\]\S* ([\w\-]+)\(")
+
+
+def test_served_arena_programs_update_it_in_place_on_v5e(one_chip):
+    """``ServeLoop``'s decode step and slot write at the chat cell's width:
+    each aliases the whole arena from input to output, and the step holds
+    no copy and no masked select over the stacked cache or a layer of it,
+    which is what the step spent its arena time on before it was donated."""
+    run = RunConfig(remat="none", attention_impl="xla", decode_attention_impl="kernel")
+    batch, max_len = 16, 1024
+    loop = ServeLoop(QWEN3, run, None, batch=batch, max_len=max_len, mode="arena")
+    put = lambda tree: jax.tree.map(lambda x: _on(one_chip, x.shape, x.dtype), tree)
+    params = put(jax.eval_shape(lambda p: M.serving_params(QWEN3, p), M.model_shapes(QWEN3)))
+    arena = put(jax.eval_shape(lambda: M.init_cache(QWEN3, batch, max_len)))
+    one = put(jax.eval_shape(lambda: M.init_cache(QWEN3, 1, max_len)))
+    step = loop._decode_arena.lower(
+        params, arena, _on(one_chip, (batch, 1), jnp.int32), _on(one_chip, (batch,), jnp.bool_)
+    ).compile()
+    write = loop._write_slot.lower(arena, one, _on(one_chip, (), jnp.int32)).compile()
+    arena_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(arena))
+    for compiled in (step, write):  # device tiling pads the (16,) positions
+        assert compiled.memory_analysis().alias_size_in_bytes >= arena_bytes
+    found = [(name, op) for name, op in ARENA_SHAPED.findall(step.as_text())
+             if op in ("copy", "select") or (op == "fusion" and re.search("copy|select", name))]
+    assert not found, found
